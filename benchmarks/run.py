@@ -24,6 +24,9 @@ import time
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=5_000,
                     help="dataset size (paper scale: 100k+; default fits CI)")

@@ -196,6 +196,7 @@ def trim_graph(g: KNNGraph, new_capacity: int) -> KNNGraph:
     )
 
 
+@jax.jit
 def rebuild_reverse(g: KNNGraph) -> KNNGraph:
     """Recompute rev lists from forward lists (checkpoint-restore / repair).
 
@@ -203,7 +204,8 @@ def rebuild_reverse(g: KNNGraph) -> KNNGraph:
     owners.  The forward twin's λ rides along as a second payload, so the
     ``rev_lam`` snapshot is exact at rebuild time.  Pure function of the
     forward graph — used to verify the incremental ring-buffer maintenance
-    in tests.
+    in tests.  Jitted: run op by op, its scans would compile dozens of
+    programs on a TPU.
     """
     cap, k = g.nbr_ids.shape
     R = g.rev_capacity

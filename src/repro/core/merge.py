@@ -127,6 +127,7 @@ def merge_candidates(
     )
 
 
+@jax.jit
 def append_reverse(
     rev_ids: Array,
     rev_lam: Array,
@@ -147,7 +148,9 @@ def append_reverse(
         time (the rev_lam payload); defaults to 0 (fresh edges join with
         λ = 0 per Alg. 3).
 
-    Returns updated (rev_ids, rev_lam, rev_ptr).
+    Returns updated (rev_ids, rev_lam, rev_ptr).  Jitted, like
+    ``graph.rebuild_reverse``: eager callers (coarse-level assignment) would
+    otherwise compile its scans op by op.
     """
     cap, R = rev_ids.shape
     if lam is None:

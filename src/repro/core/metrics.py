@@ -28,6 +28,13 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# Every matmul below contracts at full fp32 precision.  On a TPU the default
+# precision contracts f32 in bf16 passes, which the norms expansion turns into
+# errors on exactly the small distances that rank nearest neighbours — and
+# this registry is the exact reference every recall audit compares against.
+# On the CPU HIGHEST is what the default already computes.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 # metric name -> (pairwise_fn, needs_matmul)
 _REGISTRY: Dict[str, Callable[[Array, Array], Array]] = {}
 
@@ -74,14 +81,16 @@ def _l2(q: Array, x: Array) -> Array:
     x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)  # (m, 1)
     xn = jnp.sum(x * x, axis=-1, keepdims=True).T  # (1, n)
-    d = qn + xn - 2.0 * (q @ x.T)
+    d = qn + xn - 2.0 * jnp.matmul(q, x.T, precision=_HIGHEST)
     return jnp.maximum(d, 0.0)
 
 
 @register("ip")
 def _ip(q: Array, x: Array) -> Array:
     # Negative inner product (so that smaller == closer holds).
-    return -(q.astype(jnp.float32) @ x.astype(jnp.float32).T)
+    return -jnp.matmul(
+        q.astype(jnp.float32), x.astype(jnp.float32).T, precision=_HIGHEST
+    )
 
 
 @register("cosine")
@@ -90,7 +99,7 @@ def _cosine(q: Array, x: Array) -> Array:
     x = x.astype(jnp.float32)
     qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
     xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
-    return 1.0 - qn @ xn.T
+    return 1.0 - jnp.matmul(qn, xn.T, precision=_HIGHEST)
 
 
 @register("l1")

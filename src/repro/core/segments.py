@@ -10,8 +10,8 @@ rank elements within their segment, and scatter the first R per segment into
 a dense (num_segments, R) buffer.
 
 This module is that idiom, written once against stable JAX primitives
-(``jax.lax.associative_scan`` — the old copies used ``jnp.maximum.accumulate``
-which no longer exists).  Conventions:
+(``lax.cummax`` and ``jax.lax.associative_scan`` — the old copies used
+``jnp.maximum.accumulate`` which no longer exists).  Conventions:
 
 * key columns are **sorted ascending**; callers sort first (``jnp.argsort`` /
   ``jnp.lexsort``) because they usually need the permutation anyway;
@@ -37,13 +37,18 @@ def segment_starts(sorted_keys: Array) -> Array:
 
 
 def running_max(values: Array) -> Array:
-    """Inclusive prefix maximum along axis 0 (associative scan)."""
-    return jax.lax.associative_scan(jnp.maximum, values)
+    """Inclusive prefix maximum along axis 0.
+
+    ``lax.cummax`` is an associative scan everywhere but on a TPU, where it
+    lowers to one windowed reduction; the scan's dozens of strided slices
+    over a whole graph's edge list are slow to compile there.
+    """
+    return jax.lax.cummax(values, axis=0)
 
 
 def running_min(values: Array) -> Array:
-    """Inclusive prefix minimum along axis 0 (associative scan)."""
-    return jax.lax.associative_scan(jnp.minimum, values)
+    """Inclusive prefix minimum along axis 0 (see ``running_max``)."""
+    return jax.lax.cummin(values, axis=0)
 
 
 def _segmented_combine(op):

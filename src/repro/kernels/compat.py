@@ -1,16 +1,9 @@
-"""Version-portability shim for the Pallas/TPU surface (and adjacent JAX
-API drift).
+"""The one import point for the Pallas/TPU surface and adjacent JAX APIs.
 
-JAX renames and moves things between minor versions; every breakage the seed
-suffered traced back to a call site touching a moved attribute directly
-(``pltpu.CompilerParams`` vs ``pltpu.TPUCompilerParams``, ``jax.shard_map``
-vs ``jax.experimental.shard_map.shard_map``, ``jax.sharding.AxisType``).
-This module is the single point of truth: kernel and parallelism code imports
-*only* from here, so the next rename is a one-line fix instead of a red
-test suite.
-
-Everything is resolved by feature detection at import time — no version
-string parsing.
+Kernel and parallelism code imports the Pallas TPU memory spaces, DMA
+helpers, compiler params, ``shard_map`` and mesh construction only from
+here, so an API move in a later JAX is a change to this file alone.  It
+targets the installed JAX (0.9) and carries no fallbacks for older ones.
 """
 
 from __future__ import annotations
@@ -21,6 +14,7 @@ from typing import Optional, Sequence
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
 # ---------------------------------------------------------------------------
 # Pallas TPU surface
@@ -30,24 +24,18 @@ from jax.experimental.pallas import tpu as pltpu
 # pltpu attributes directly.
 VMEM = pltpu.VMEM
 SMEM = pltpu.SMEM
-ANY = getattr(pltpu, "ANY", getattr(pl, "ANY", None))
+ANY = pl.ANY
 SemaphoreType = pltpu.SemaphoreType
 make_async_copy = pltpu.make_async_copy
-PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-
-# Renamed in newer JAX: TPUCompilerParams -> CompilerParams.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def compiler_params(
     *, dimension_semantics: Optional[Sequence[str]] = None, **kwargs
 ):
-    """TPU compiler params under whichever class name this JAX exposes."""
+    """``pltpu.CompilerParams`` with ``dimension_semantics`` as a tuple."""
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _COMPILER_PARAMS_CLS(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def on_tpu() -> bool:
@@ -61,37 +49,20 @@ def default_interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# shard_map / mesh drift
+# shard_map / mesh
 # ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):  # newer JAX: top-level, check_vma kwarg
-    _shard_map_impl = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # older JAX: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_rep"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across its experimental -> stable migration.
-
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old); both default
-    off here because the k-NN wave step intentionally mixes replicated and
-    sharded outputs.
-    """
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check},
+    """``jax.shard_map`` with ``check_vma`` off by default: the k-NN wave
+    step intentionally mixes replicated and sharded outputs."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with explicit-Auto axis types."""
     return jax.make_mesh(
         tuple(axis_shapes), tuple(axis_names),
         axis_types=(AxisType.Auto,) * len(tuple(axis_names)),

@@ -18,7 +18,8 @@ Grid: ``(m_tiles, n_tiles, d_tiles)`` with the reduction axis innermost
 consecutively; partials live in VMEM scratch, the HBM output is written once.
 
 Block shapes are multiples of (8, 128) so fp32 tiles are register-aligned and
-the MXU sees 128x128-aligned operands.
+the MXU sees 128x128-aligned operands.  The GEMM contracts at HIGHEST
+precision, as every engine matmul does (``gather_dist.block_distance``).
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import compat
+from repro.kernels.gather_dist import PRECISION as _PRECISION
 
 Array = jax.Array
 
@@ -51,7 +53,8 @@ def _dist_kernel_mxu(q_ref, x_ref, o_ref, acc_ref, qsq_ref, xsq_ref, *, metric: 
     q = q_ref[...].astype(jnp.float32)  # (bm, bd)
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     acc_ref[...] += jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())),
+        precision=_PRECISION, preferred_element_type=jnp.float32,
     )
     if metric == "l2":
         qsq_ref[...] += jnp.sum(q * q, axis=1, keepdims=True)
@@ -81,7 +84,8 @@ def _dist_kernel_mxu_cached(q_ref, x_ref, xn_ref, o_ref, acc_ref, qsq_ref, *, nd
     q = q_ref[...].astype(jnp.float32)  # (bm, bd)
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     acc_ref[...] += jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())),
+        precision=_PRECISION, preferred_element_type=jnp.float32,
     )
     qsq_ref[...] += jnp.sum(q * q, axis=1, keepdims=True)
 
@@ -95,8 +99,10 @@ def _dist_kernel_mxu_cached(q_ref, x_ref, xn_ref, o_ref, acc_ref, qsq_ref, *, nd
 def _dist_kernel_vpu(q_ref, x_ref, o_ref, acc_ref, *, metric: str, nd: int, rows_per_step: int):
     """VPU path: accumulate sum-reductions of |q - x| / chi2 over d tiles.
 
-    The (bm, bn, bd) broadcast is walked in row-strips of the x block so the
-    VMEM-resident intermediate stays at (bm, rows_per_step, bd).
+    The (bm, bn, bd) broadcast is walked in row-strips of the q block so the
+    VMEM-resident intermediate stays at (rows_per_step, bn, bd).  Strips are
+    sliced from the refs at sublane-aligned offsets: the TPU compiler takes a
+    dynamic slice of a ref, never of a value.
     """
     k = pl.program_id(2)
 
@@ -104,28 +110,25 @@ def _dist_kernel_vpu(q_ref, x_ref, o_ref, acc_ref, *, metric: str, nd: int, rows
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)  # (bm, bd)
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
-    bn = x.shape[0]
-    nsteps = bn // rows_per_step
+    bm = q_ref.shape[0]
 
-    def body(i, acc):
-        xs = jax.lax.dynamic_slice_in_dim(x, i * rows_per_step, rows_per_step, 0)
-        diff = q[:, None, :] - xs[None, :, :]  # (bm, rps, bd)
+    def body(i, _):
+        rows = pl.ds(pl.multiple_of(i * rows_per_step, rows_per_step), rows_per_step)
+        qs = q_ref[rows, :].astype(jnp.float32)  # (rps, bd)
+        diff = qs[:, None, :] - x[None, :, :]  # (rps, bn, bd)
         if metric == "l1":
             part = jnp.sum(jnp.abs(diff), axis=-1)
         else:  # chi2
-            den = q[:, None, :] + xs[None, :, :]
+            den = qs[:, None, :] + x[None, :, :]
             part = jnp.sum(
                 jnp.where(den > 1e-12, diff * diff / jnp.maximum(den, 1e-12), 0.0),
                 axis=-1,
             )
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc, jax.lax.dynamic_slice_in_dim(acc, i * rows_per_step, rows_per_step, 1) + part,
-            i * rows_per_step, 1,
-        )
+        acc_ref[rows, :] += part
+        return ()
 
-    acc_ref[...] = jax.lax.fori_loop(0, nsteps, body, acc_ref[...])
+    jax.lax.fori_loop(0, bm // rows_per_step, body, ())
 
     @pl.when(k == nd - 1)
     def _done():
@@ -212,7 +215,7 @@ def pairwise_distance(
             compat.VMEM((1, bn), jnp.float32),
         ]
     elif kernel_metric in VPU_METRICS:
-        rows = min(8, bn)
+        rows = 8  # bm is a multiple of 8
         kern = functools.partial(
             _dist_kernel_vpu, metric=kernel_metric, nd=grid[2], rows_per_step=rows
         )
@@ -231,6 +234,7 @@ def pairwise_distance(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="pairwise_distance",
     )(*operands)
     out = out[:m, :n]
     if metric == "cosine":

@@ -25,11 +25,15 @@ unfused op chain.  Both implementations consume the same helpers; the parity
 suite (``tests/test_expand_parity.py``) pins them bit-identical in interpret
 mode.  Dispatch between them is ``kernels.ops.expand_step``:
 
-  * TPU (``use_pallas`` unset or True): compiled fused kernel;
-  * ``use_pallas=True`` off-TPU: the same kernel, interpret mode (the
+  * ``dispatch="auto"`` on TPU: ``expand_reference`` with the compiled
+    ``gather_dist`` kernel as its distance phase (for fp32 tables with d a
+    multiple of 128; the XLA gather otherwise);
+  * ``"pallas"``: this fused kernel — compiled on TPU (where it does not
+    compile yet, see below), interpret mode elsewhere;
+  * ``"interpret"``: the fused kernel in interpret mode everywhere (the
     correctness net the tests sweep);
-  * ``use_pallas=False`` / unset off-TPU: ``expand_reference`` (XLA fuses the
-    whole step into the jitted search loop — the fast CPU path).
+  * ``"reference"``, and ``"auto"`` off-TPU: ``expand_reference`` with the
+    XLA gather (XLA fuses the whole step into the jitted search loop).
 
 Candidate *generation* (graph-row gathers + λ/alive masking,
 ``core.search._candidates_from_expansion``) stays outside the kernel: it is a
@@ -37,9 +41,15 @@ handful of dense row gathers XLA already handles well, and keeping it shared
 between both paths means the kernel boundary is exactly the memory-bound
 probe/distance/merge chain the ROADMAP's scanning-rate numbers depend on.
 
-Compiled-mode note: the vector phase leans on in-VMEM gather/scatter and a
-row-wise ``lax.top_k`` — Mosaic support for these lowers with recent JAX; the
-interpret fallback (selected automatically off-TPU) is the portability net.
+What lowers for a TPU today (JAX 0.9, checked against a described v5e by
+``tests/test_tpu_compile.py``): the operand blocking and phase 1, the
+blocked row-DMA distance phase, which is the ``gather_dist`` kernel's own
+body.  Phase 2 does not: its first hash gather (``take_along_axis`` in
+``hash_probe_state``) is refused ("Shape mismatch in input, indices and
+output"), and ``lax.top_k`` and the hash-record scatter are primitives the
+Mosaic lowering does not implement.  A compiled fused step needs phase 2
+rewritten for the vector unit (scalar-indexed probes, a merge network in
+place of ``top_k``); until then ``ops.expand_step`` keeps ``"auto"`` off it.
 """
 
 from __future__ import annotations
@@ -250,7 +260,7 @@ def expand_reference(
 
 
 def _fused_expand_kernel(
-    idx_ref,  # (B, C_pad) int32, SMEM (scalar prefetch) — drives the DMAs
+    idx_ref,  # (1, C_pad) int32 SMEM — drives the DMAs
     cand_ref,  # (1, C_pad) int32 VMEM — same ids, vector phase operand
     q_ref,  # (1, d) VMEM
     xn_ref,  # (1, C_pad) f32 VMEM — gathered ‖x‖² (the norm cache)
@@ -274,7 +284,6 @@ def _fused_expand_kernel(
         (x_ref, obi_ref, obd_ref, obe_ref, ovi_ref, ovd_ref, oc_ref,
          dist_buf, tile_buf, sems) = rest
         xs_ref = None
-    b = pl.program_id(0)
     q = q_ref[...].astype(jnp.float32)  # (1, d)
 
     # -- phase 1: blocked candidate gather + one-shot tile reductions --------
@@ -286,7 +295,7 @@ def _fused_expand_kernel(
     # DMA loop with no data-dependent control flow.  Counted comps (phase 2)
     # only charge fresh candidates, matching the unfused path.
     _gather_dist.blocked_gather_phase(
-        b, idx_ref, cand_ref, q, xn_ref, x_ref, dist_buf, tile_buf, sems,
+        idx_ref, cand_ref, q, xn_ref, x_ref, dist_buf, tile_buf, sems,
         n_blocks=n_blocks, c_blk=c_blk, metric=metric, xs_ref=xs_ref,
     )
 
@@ -371,8 +380,10 @@ def fused_expand(
         _fused_expand_kernel, n_cand=C, n_blocks=cp // cb, c_blk=cb,
         metric=kernel_metric, probes=probes, quantized=quantized,
     )
-    row = lambda w: pl.BlockSpec((1, w), lambda i, idx_ref: (i, 0))
+    row = _gather_dist.lane_spec
+    lanes = lambda a: a[:, None, :]  # (B, w) -> (B, 1, w): see lane_spec
     in_specs = [
+        row(cp, compat.SMEM),  # cands (DMA addresses)
         row(cp),  # cands (vector phase; first C entries are the originals)
         row(d),  # q
         row(cp),  # xn
@@ -396,10 +407,11 @@ def fused_expand(
     if quantized:
         in_specs.append(row(cp))  # xs (gathered int8 dequant scales)
         operands.append(_gather_dist.gathered_row_scales(cands_p, enc.scale))
+    operands = [lanes(a) for a in operands]
     in_specs.append(pl.BlockSpec(memory_space=compat.ANY))  # x
     operands.append(x_eng)
-    grid_spec = compat.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    outs = pl.pallas_call(
+        kern,
         grid=(B,),
         in_specs=in_specs,
         out_specs=[row(e), row(e), row(e), row(H), row(H), row(1)],
@@ -408,19 +420,16 @@ def fused_expand(
             compat.VMEM((2, cb, d), x_eng.dtype),  # tile in storage dtype
             compat.SemaphoreType.DMA((2, cb)),
         ],
-    )
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, e), jnp.int32),
-            jax.ShapeDtypeStruct((B, e), jnp.float32),
-            jax.ShapeDtypeStruct((B, e), jnp.int32),
-            jax.ShapeDtypeStruct((B, H), jnp.int32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, e), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, e), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, e), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, H), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="fused_expand",
     )(*operands)
-    bi, bd, be, vi, vd, comps = outs
+    bi, bd, be, vi, vd, comps = (o[:, 0, :] for o in outs)
     return bi, bd, be > 0, vi, vd, comps[:, 0]

@@ -24,13 +24,24 @@ bit-identical per comparison (pinned by the expansion parity suite).
 Layout
 ------
 * grid = (B,): one grid step per query; Pallas pipelines steps.
-* ``idx`` (B, C_pad) int32: scalar-prefetch operand (SMEM) driving the DMAs;
-  the same ids ride again as a VMEM operand for vector-phase masking.
+* ``idx`` (B, C_pad) int32: one (1, C_pad) SMEM block per grid step drives
+  the DMAs (a scalar prefetch of the whole (B, C_pad) table would not fit
+  the 1 MiB SMEM at wave sizes); the same ids ride again as a VMEM operand
+  for vector-phase masking.
 * ``x`` (n, d): stays in HBM/ANY; rows are moved with
   ``pltpu.make_async_copy`` into a 2-slot (C_blk, d) VMEM scratch (block
   j+1 is in flight while block j is reduced).
 * ``xn`` (B, C_pad) float32: gathered squared norms of the candidate rows.
 * out block (1, C_pad) float32; the wrapper slices back to C.
+* Per-query operands travel as (B, 1, w) arrays blocked ``(None, 1, w)``
+  (``lane_spec``): the TPU compiler requires a block's last two dimensions
+  to be (8, 128)-divisible or whole, which a (1, w) block of a (B, w) array
+  is not.  The kernel still sees (1, w) refs.
+
+The TPU compiler accepts the single-row HBM slices of the row DMAs only for
+fp32 tables with d a multiple of 128 (bf16/int8 rows are sub-tile along the
+sublane axis; other widths are lane-misaligned).  ``kernel_fits`` states that
+rule; ``kernels.ops`` routes every other table to the XLA engine on TPU.
 
 Candidate lists are padded to a multiple of the block width with -1; negative
 ids are padding and their lanes are forced to +inf (the convention the search
@@ -53,6 +64,25 @@ Array = jax.Array
 # Widest candidate block one tile reduction covers: matches the MXU's 128
 # systolic rows; shorter candidate lists use one exact-width block.
 _MAX_BLOCK_C = 128
+
+# Contraction precision of every engine matmul (see ``block_distance``).
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def kernel_fits(dtype, d: int) -> bool:
+    """Whether the compiled row-DMA gather takes an (n, d) table of ``dtype``.
+
+    Only fp32 tables whose width is a multiple of 128 lanes: a one-row HBM
+    slice of a bf16/int8 table is not aligned to its (16|32, 128) tiling, and
+    a row of any other width is not aligned to 128 lanes.
+    """
+    return jnp.dtype(dtype) == jnp.float32 and d % 128 == 0
+
+
+def lane_spec(w: int, memory_space=None) -> pl.BlockSpec:
+    """(None, 1, w) block of a (B, 1, w) per-query operand at grid step i."""
+    kw = {} if memory_space is None else {"memory_space": memory_space}
+    return pl.BlockSpec((None, 1, w), lambda i: (i, 0, 0), **kw)
 
 
 def block_c(n_cand: int) -> int:
@@ -91,6 +121,13 @@ def block_distance(
     Returns (1, C_blk) float32 distances.  ``"dot"`` is the raw inner
     product; ``"cos"`` expects a pre-normalized query and *raw* data rows —
     the cached norm supplies the denominator.
+
+    Precision: the ``q·x`` contraction runs at ``PRECISION`` (HIGHEST, full
+    fp32), like every engine matmul in ``kernels`` and ``core.metrics``.  On
+    a TPU the default precision contracts f32 in bf16 passes, and the norms
+    decomposition then loses the small distances that rank the nearest
+    neighbours.  On the CPU HIGHEST is what the default already computes, so
+    fp32 results there are unchanged bit for bit.
     """
     q = q.astype(jnp.float32)
     tile = tile.astype(jnp.float32)
@@ -99,7 +136,7 @@ def block_distance(
     if metric in ("l2", "ip", "dot", "cos"):
         dots = jax.lax.dot_general(
             q, tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=PRECISION, preferred_element_type=jnp.float32,
         )  # (1, C_blk) — one MXU pass covers the whole block
         if xscale is not None:
             dots = dots * xscale
@@ -155,8 +192,7 @@ def gathered_row_scales(idx: Array, row_scale: Array) -> Array:
 
 
 def blocked_gather_phase(
-    b,  # scalar: which query lane (grid position)
-    idx_ref,  # (B, C_pad) int32 SMEM (scalar prefetch) — drives the DMAs
+    idx_ref,  # (1, C_pad) int32 SMEM — this lane's ids, drive the DMAs
     ids_ref,  # (1, C_pad) int32 VMEM — same ids, vector-phase masking
     q,  # (1, d) float32 (already read from its ref)
     xn_ref,  # (1, C_pad) float32 VMEM — gathered ‖x‖² per candidate
@@ -185,7 +221,7 @@ def blocked_gather_phase(
     """
 
     def row_copy(blk, r, slot):
-        rid = jnp.maximum(idx_ref[b, blk * c_blk + r], 0)
+        rid = jnp.maximum(idx_ref[0, blk * c_blk + r], 0)
         return compat.make_async_copy(
             x_ref.at[pl.ds(rid, 1)], tile_buf.at[slot, pl.ds(r, 1)],
             sems.at[slot, r],
@@ -228,7 +264,7 @@ def blocked_gather_phase(
 
 
 def _gather_dist_kernel(
-    idx_ref,  # (B, C_pad) int32, SMEM (scalar prefetch)
+    idx_ref,  # (1, C_pad) int32 SMEM
     ids_ref,  # (1, C_pad) int32 VMEM
     q_ref,  # (1, d) VMEM
     xn_ref,  # (1, C_pad) VMEM
@@ -243,10 +279,9 @@ def _gather_dist_kernel(
     else:
         x_ref, o_ref, tile_buf, sems = rest
         xs_ref = None
-    b = pl.program_id(0)
     q = q_ref[...].astype(jnp.float32)  # (1, d)
     blocked_gather_phase(
-        b, idx_ref, ids_ref, q, xn_ref, x_ref, o_ref, tile_buf, sems,
+        idx_ref, ids_ref, q, xn_ref, x_ref, o_ref, tile_buf, sems,
         n_blocks=n_blocks, c_blk=c_blk, metric=metric, xs_ref=xs_ref,
     )
 
@@ -296,19 +331,20 @@ def gather_distance(
         raise ValueError("int8 tables need the exact sq_norms cache")
     xn = gathered_sq_norms(x, idx, sq_norms)  # (b, cp)
     quantized = x.dtype == jnp.int8
-    operands = [idx, idx, q, xn]
-    row = lambda w: pl.BlockSpec((1, w), lambda i, idx_ref: (i, 0))
+    lanes = lambda a: a[:, None, :]  # (b, w) -> (b, 1, w): see lane_spec
+    operands = [lanes(idx), lanes(idx), lanes(q), lanes(xn)]
     in_specs = [
-        row(cp),  # ids (vector phase masking)
-        row(d),  # q
-        row(cp),  # xn
+        lane_spec(cp, compat.SMEM),  # ids (DMA addresses)
+        lane_spec(cp),  # ids (vector phase masking)
+        lane_spec(d),  # q
+        lane_spec(cp),  # xn
     ]
     if quantized:
         if row_scale is None:
             raise ValueError("int8 tables need the row_scale table")
         xs = gathered_row_scales(idx, row_scale)  # (b, cp)
-        operands.append(xs)
-        in_specs.append(row(cp))
+        operands.append(lanes(xs))
+        in_specs.append(lane_spec(cp))
     operands.append(x)
     in_specs.append(pl.BlockSpec(memory_space=compat.ANY))  # x
 
@@ -316,20 +352,17 @@ def gather_distance(
         _gather_dist_kernel, n_blocks=cp // cb, c_blk=cb,
         metric=kernel_metric, quantized=quantized,
     )
-    grid_spec = compat.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    out = pl.pallas_call(
+        kern,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=row(cp),
+        out_specs=lane_spec(cp),
         scratch_shapes=[
             compat.VMEM((2, cb, d), x.dtype),  # tile lands in storage dtype
             compat.SemaphoreType.DMA((2, cb)),
         ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, cp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, cp), jnp.float32),
         interpret=interpret,
+        name="gather_distance",
     )(*operands)
-    return out[:, :c]
+    return out[:, 0, :c]

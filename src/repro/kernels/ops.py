@@ -6,17 +6,33 @@ execution-surface policies of the framework are resolved HERE and only here:
 * **dispatch** — which engine implementation runs.  One enum replaces the old
   tri-state ``use_pallas`` flag:
 
-    - ``"auto"``      compiled Pallas kernel on TPU, pure-JAX reference
+    - ``"auto"``      a compiled Pallas kernel on TPU where the kernel compiles
+                      for the operands (rules below), the pure-JAX reference
                       elsewhere (the old ``use_pallas=None``);
     - ``"pallas"``    the Pallas kernel — compiled on TPU, interpret mode
-                      elsewhere (the old ``use_pallas=True``);
+                      elsewhere (the old ``use_pallas=True``).  On TPU it
+                      raises where the chip's compiler refuses the kernel;
     - ``"interpret"`` the Pallas kernel in interpret mode everywhere (what
                       kernel-correctness tests sweep, even on TPU);
     - ``"reference"`` the pure-JAX reference path everywhere (the old
                       ``use_pallas=False``).
 
-  The legacy ``use_pallas`` keyword is still accepted (None/True/False map to
-  auto/pallas/reference); config-level deprecation lives in
+  What ``"auto"`` runs on TPU is decided by static predicates on shapes and
+  dtypes, never by catching a compile error:
+
+    - ``pairwise_distance``: the tiled kernel (fp32 tiles).
+    - ``gather_distance``: the blocked row-DMA kernel when
+      ``gather_dist.kernel_fits(table dtype, d)`` — fp32 tables with d a
+      multiple of 128 — and the XLA reference for bf16/int8 tables or any
+      other width, whose one-row HBM slices the compiler refuses.
+    - ``expand_step``: never the fused kernel, whose vector phase (hash
+      gathers, scatter, ``lax.top_k``) does not lower to Mosaic.  It runs
+      ``expand.expand_reference`` — the XLA probe/record/merge — with
+      ``pallas_distances`` set by the same ``gather_distance`` predicate.
+
+  ``engines`` reports these choices for a table, so callers can print what
+  ran.  The legacy ``use_pallas`` keyword is still accepted (None/True/False
+  map to auto/pallas/reference); config-level deprecation lives in
   ``core.search.SearchConfig``.
 
 * **precision** — which candidate representation the engine fetches
@@ -56,13 +72,19 @@ DISPATCHES = ("auto", "pallas", "interpret", "reference")
 
 
 def resolve_dispatch(
-    dispatch: Optional[str] = None, use_pallas: Optional[bool] = None
+    dispatch: Optional[str] = None,
+    use_pallas: Optional[bool] = None,
+    *,
+    fits: bool = True,
 ) -> tuple[bool, bool]:
     """The one resolution point for the execution-path enum.
 
-    Returns ``(use_kernel, interpret)``.  ``dispatch=None`` falls back to the
-    legacy ``use_pallas`` tri-state (None -> auto, True -> pallas, False ->
-    reference) so old callers and old snapshots keep working.
+    Returns ``(use_kernel, interpret)``.  ``fits`` is the op's static
+    predicate that its kernel compiles on TPU for these operands; only
+    ``"auto"`` consults it (see the module docstring for each op's rule).
+    ``dispatch=None`` falls back to the legacy ``use_pallas`` tri-state
+    (None -> auto, True -> pallas, False -> reference) so old callers and
+    old snapshots keep working.
     """
     if dispatch is None:
         if use_pallas is None:
@@ -70,7 +92,7 @@ def resolve_dispatch(
         else:
             dispatch = "pallas" if use_pallas else "reference"
     if dispatch == "auto":
-        return _on_tpu(), False
+        return _on_tpu() and fits, False
     if dispatch == "pallas":
         return True, not _on_tpu()
     if dispatch == "interpret":
@@ -80,6 +102,54 @@ def resolve_dispatch(
     raise ValueError(
         f"unknown dispatch {dispatch!r}; expected one of {DISPATCHES}"
     )
+
+
+def engines(
+    dispatch: Optional[str], dtype, d: int
+) -> dict[str, str]:
+    """Which engine each op runs for an (n, d) table of ``dtype``.
+
+    Values: ``"pallas"`` (compiled kernel), ``"pallas-interpret"``,
+    ``"xla"`` (pure-JAX reference), and for ``expand_step`` also
+    ``"xla+pallas-gather"`` (the XLA probe/merge around the compiled gather
+    kernel).  Computed by the same predicates the dispatchers use.
+    """
+
+    def name(use_kernel, interpret):
+        if not use_kernel:
+            return "xla"
+        return "pallas-interpret" if interpret else "pallas"
+
+    gather = _gather_engine(dispatch, None, dtype, d)
+    fused = _fused_expand_engine(dispatch, None)
+    if fused[0]:
+        expand = name(*fused)
+    else:
+        expand = "xla+pallas-gather" if gather[0] else "xla"
+    return {
+        "pairwise_distance": name(*resolve_dispatch(dispatch)),
+        "gather_distance": name(*gather),
+        "expand_step": expand,
+    }
+
+
+def _gather_engine(
+    dispatch: Optional[str], use_pallas: Optional[bool], dtype, d: int
+) -> tuple[bool, bool]:
+    """``resolve_dispatch`` for the gather kernel over an (n, d) table of
+    ``dtype``: its one-row HBM slices compile only where
+    ``gather_dist.kernel_fits``."""
+    return resolve_dispatch(
+        dispatch, use_pallas, fits=_gather_dist.kernel_fits(dtype, d)
+    )
+
+
+def _fused_expand_engine(
+    dispatch: Optional[str], use_pallas: Optional[bool]
+) -> tuple[bool, bool]:
+    """``resolve_dispatch`` for the fused expansion kernel: ``"auto"`` never
+    selects it, since its vector phase does not lower to Mosaic."""
+    return resolve_dispatch(dispatch, use_pallas, fits=False)
 
 
 def pairwise_distance(
@@ -104,7 +174,7 @@ def pairwise_distance(
     tiles, not the expansion hot loop, and the Pallas pairwise kernel stays
     fp32-only.
     """
-    use_kernel, _ = resolve_dispatch(dispatch, use_pallas)
+    use_kernel, interpret = resolve_dispatch(dispatch, use_pallas)
     if enc is not None and precision != "fp32":
         return _ref.pairwise_distance(
             q, x, metric, x_sq_norms=x_sq_norms, enc=enc, precision=precision
@@ -112,7 +182,7 @@ def pairwise_distance(
     if use_kernel:
         return _distance.pairwise_distance(
             q, x, metric=metric, x_sq_norms=x_sq_norms,
-            bm=bm, bn=bn, bd=bd, interpret=not _on_tpu(),
+            bm=bm, bn=bn, bd=bd, interpret=interpret,
         )
     return _ref.pairwise_distance(q, x, metric, x_sq_norms=x_sq_norms)
 
@@ -138,14 +208,16 @@ def gather_distance(
     in-kernel tile path has no code-table form, and the exact re-rank
     composes in ``expand_step``.
     """
-    use_kernel, interpret = resolve_dispatch(dispatch, use_pallas)
     compressed = enc is not None and precision != "fp32"
     if compressed and precision == "pq":
         return _ref.gather_distance(
             q, x, idx, metric, sq_norms=sq_norms, enc=enc, precision=precision
         )
+    x_eng = enc.data if compressed else x
+    use_kernel, interpret = _gather_engine(
+        dispatch, use_pallas, x_eng.dtype, x_eng.shape[1]
+    )
     if use_kernel:
-        x_eng = enc.data if compressed else x
         row_scale = enc.scale if compressed and precision == "int8" else None
         return _gather_dist.gather_distance(
             q, x_eng, idx, metric=metric, sq_norms=sq_norms,
@@ -259,8 +331,10 @@ def expand_step(
             use_pallas=use_pallas, dispatch=dispatch, enc=enc,
             rerank_keep=rerank_keep,
         )
-    use_kernel, interpret = resolve_dispatch(dispatch, use_pallas)
     compressed = enc is not None and precision != "fp32"
+    # where the fused kernel is not selected (always under "auto"), the XLA
+    # op chain runs around the gather engine
+    use_kernel, interpret = _fused_expand_engine(dispatch, use_pallas)
     if use_kernel:
         return _expand.fused_expand(
             q, x, cands, beam_ids, beam_dist, beam_exp, vis_ids, vis_dist,
@@ -269,11 +343,16 @@ def expand_step(
             precision=precision if compressed else "fp32",
             interpret=interpret,
         )
+    x_eng = enc.data if compressed else x
+    gather_kernel, gather_interpret = _gather_engine(
+        dispatch, use_pallas, x_eng.dtype, x_eng.shape[1]
+    )
     return _expand.expand_reference(
         q, x, cands, beam_ids, beam_dist, beam_exp, vis_ids, vis_dist,
         metric=metric, probes=hash_probes, sq_norms=sq_norms,
         enc=enc if compressed else None,
         precision=precision if compressed else "fp32",
+        pallas_distances=gather_kernel, interpret=gather_interpret,
     )
 
 

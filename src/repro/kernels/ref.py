@@ -7,7 +7,8 @@ the SAME norms-decomposed blocked formula as the Pallas engine
 (``kernels.gather_dist.block_distance``) — ``‖q‖² + ‖x‖² − 2·q·x`` with the
 ``‖x‖²`` term served from the graph-resident cache when the caller has one —
 so the CPU production path and the TPU kernel agree to float tolerance and
-neither recomputes norms per iteration.
+neither recomputes norms per iteration.  Matmuls contract at the engine's
+``gather_dist.PRECISION`` (HIGHEST), so these oracles stay exact on a TPU.
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import metrics
 from repro.kernels import precision as _precision
+from repro.kernels.gather_dist import PRECISION as _PRECISION
 
 Array = jax.Array
 
@@ -41,14 +43,14 @@ def _chunked_dots(qf: Array, cand: Array) -> Array:
     if C <= _CHUNK_C or C % _CHUNK_C:
         return jax.lax.dot_general(
             qf, cand.astype(jnp.float32), dn,
-            preferred_element_type=jnp.float32,
+            precision=_PRECISION, preferred_element_type=jnp.float32,
         )
     blocks = jnp.moveaxis(cand.reshape(B, C // _CHUNK_C, _CHUNK_C, d), 1, 0)
 
     def body(carry, blk):
         return carry, jax.lax.dot_general(
             qf, blk.astype(jnp.float32), dn,
-            preferred_element_type=jnp.float32,
+            precision=_PRECISION, preferred_element_type=jnp.float32,
         )
 
     _, out = jax.lax.scan(body, 0, blocks)
@@ -81,7 +83,9 @@ def pairwise_distance(
         xf = x.astype(jnp.float32)
         qn = jnp.sum(qf * qf, axis=-1, keepdims=True)  # (m, 1)
         return jnp.maximum(
-            qn + x_sq_norms.astype(jnp.float32)[None, :] - 2.0 * (qf @ xf.T), 0.0
+            qn + x_sq_norms.astype(jnp.float32)[None, :]
+            - 2.0 * jnp.matmul(qf, xf.T, precision=_PRECISION),
+            0.0,
         )
     return metrics.pairwise(metric, q, x)
 
@@ -120,7 +124,9 @@ def _pairwise_distance_compressed(
             qf = qf / jnp.maximum(
                 jnp.linalg.norm(qf, axis=-1, keepdims=True), 1e-12
             )
-        dots = qf @ enc.data.astype(jnp.float32).T
+        dots = jnp.matmul(
+            qf, enc.data.astype(jnp.float32).T, precision=_PRECISION
+        )
         if precision == "int8":
             s = enc.scale.astype(jnp.float32)
             dots = dots * jnp.where(s > 0, s, 1.0)[None, :]

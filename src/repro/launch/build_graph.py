@@ -20,10 +20,12 @@ import jax.numpy as jnp
 from repro.core import brute, construct
 from repro.core.graph import empty_graph
 from repro.data import synthetic
+from repro.launch.cache import enable_compile_cache
 from repro.train import checkpoint as ckpt_lib
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20_000)
     ap.add_argument("--d", type=int, default=32)
@@ -62,7 +64,7 @@ def main():
     x = synthetic.make(args.kind, jax.random.PRNGKey(0), args.n, args.d)
     cfg = construct.BuildConfig(
         k=args.k, metric=args.metric, wave=args.wave,
-        lgd=(args.algo == "lgd"), beam=max(40, args.k), dispatch="reference",
+        lgd=(args.algo == "lgd"), beam=max(40, args.k),
         precision=args.precision,
         seed_mode=args.seed_mode, coarse_landmarks=args.coarse_landmarks,
     )
@@ -108,8 +110,7 @@ def main():
     if args.eval:
         tids, _ = brute.brute_force_knn(
             x, x, args.k, args.metric,
-            exclude_ids=jnp.arange(args.n, dtype=jnp.int32),
-            dispatch="reference")
+            exclude_ids=jnp.arange(args.n, dtype=jnp.int32))
         r1 = float(brute.recall_at_k(g.nbr_ids[:, :1], tids[:, :1], 1))
         rk = float(brute.recall_at_k(g.nbr_ids, tids, args.k))
         print(f"graph recall@1={r1:.4f} recall@{args.k}={rk:.4f}")

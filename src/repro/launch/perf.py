@@ -1,5 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+if __name__ == "__main__":  # a process of its own: never on import
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """§Perf hillclimb driver: compile variants of the three chosen cells on the
 single-pod production mesh and record roofline deltas.

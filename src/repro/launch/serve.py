@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.cache import enable_compile_cache
 
 
 def serve_retrieval(args):
@@ -143,6 +144,7 @@ def serve_lm(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["retrieval", "lm"], default="retrieval")
     ap.add_argument("--arch", default="gemma3-1b")

@@ -247,7 +247,7 @@ class ServingLoop:
             true_ids, _ = brute.brute_force_knn(
                 self.index.items, jnp.asarray(q), k, self.index.metric,
                 n_valid=self.index.graph.n_valid, alive=self.index.graph.alive,
-                use_pallas=False,
+                dispatch="reference",
             )
             fresh = self.index.search(
                 jnp.asarray(q), self.cfg.top_k, beam=self.cfg.beam,

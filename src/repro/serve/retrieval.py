@@ -133,7 +133,8 @@ def retrieve_brute(index: OnlineIndex, interests: Array, top_k: int):
     index.flush()
     ids, dist = brute.brute_force_knn(
         index.items, interests, top_k, index.metric,
-        n_valid=index.graph.n_valid, alive=index.graph.alive, use_pallas=False,
+        n_valid=index.graph.n_valid, alive=index.graph.alive,
+        dispatch="reference",
     )
     flat_i = ids.reshape(-1)
     flat_d = dist.reshape(-1)
