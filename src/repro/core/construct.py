@@ -345,32 +345,36 @@ def wave_core(
     pos = pos.astype(jnp.int32)
     if n_real is None:
         n_real = jnp.minimum(W, n - pos).astype(jnp.int32)
-    q_ids = jnp.minimum(pos + jnp.arange(W, dtype=jnp.int32), n - 1)
-    q = x[q_ids]
-    scfg = cfg.search_config()
-    if coarse is None and scfg.seed_mode == "coarse":
-        scfg = dataclasses.replace(scfg, seed_mode="random")
-    res = search_lib.search(g, x, q, key, scfg, coarse=coarse, enc=enc)
-    res = res._replace(
-        n_comps=jnp.where(jnp.arange(W) < n_real, res.n_comps, 0)
-    )
-    g2, edges = commit_wave(g, x, pos, n_real, res, cfg)
-    comps = jnp.sum(res.n_comps)  # int32; bounded by W * C * max_iters << 2^31
-    if cfg.intra_wave and W > 1:
-        nr = n_real.astype(jnp.int32)
-        comps = comps + nr * (nr - 1) // 2
-    stats2 = BuildStats(
-        n_comps=stats.n_comps.add(comps),
-        n_waves=stats.n_waves + 1,
-        n_inserted_edges=stats.n_inserted_edges.add(edges),
-    )
-    if coarse is None:
-        return g2, stats2
-    from repro.core import hierarchy  # late: hierarchy imports construct
+    # device scopes: the benchmark reads each wave's device time by these
+    # names (the ops' HLO op_name), so they stay as they are
+    with jax.named_scope("wave_search"):
+        q_ids = jnp.minimum(pos + jnp.arange(W, dtype=jnp.int32), n - 1)
+        q = x[q_ids]
+        scfg = cfg.search_config()
+        if coarse is None and scfg.seed_mode == "coarse":
+            scfg = dataclasses.replace(scfg, seed_mode="random")
+        res = search_lib.search(g, x, q, key, scfg, coarse=coarse, enc=enc)
+        res = res._replace(
+            n_comps=jnp.where(jnp.arange(W) < n_real, res.n_comps, 0)
+        )
+    with jax.named_scope("wave_commit"):
+        g2, edges = commit_wave(g, x, pos, n_real, res, cfg)
+        comps = jnp.sum(res.n_comps)  # int32; bounded by W*C*max_iters << 2^31
+        if cfg.intra_wave and W > 1:
+            nr = n_real.astype(jnp.int32)
+            comps = comps + nr * (nr - 1) // 2
+        stats2 = BuildStats(
+            n_comps=stats.n_comps.add(comps),
+            n_waves=stats.n_waves + 1,
+            n_inserted_edges=stats.n_inserted_edges.add(edges),
+        )
+        if coarse is None:
+            return g2, stats2
+        from repro.core import hierarchy  # late: hierarchy imports construct
 
-    lanes = jnp.arange(W, dtype=jnp.int32)
-    rows = jnp.where(lanes < n_real, pos + lanes, -1)
-    coarse2 = hierarchy.note_inserted(coarse, rows, res.seed_cell)
+        lanes = jnp.arange(W, dtype=jnp.int32)
+        rows = jnp.where(lanes < n_real, pos + lanes, -1)
+        coarse2 = hierarchy.note_inserted(coarse, rows, res.seed_cell)
     return g2, stats2, coarse2
 
 
@@ -447,7 +451,9 @@ def build(
     )
 
     from repro.core import hierarchy  # late: hierarchy imports construct
+    from repro.obs import NOOP  # late: keep core importable without obs init
 
+    trk = tracker if tracker is not None else NOOP
     if initial is not None:
         g, start = initial
         if compat.donation_enabled():
@@ -459,26 +465,23 @@ def build(
             coarse = hierarchy.derive_coarse(g, x, cfg, ck)
         pre_charge = 0
     else:
-        n_seed = min(cfg.n_seed_init, n)
-        g = brute.exact_seed_graph(
-            x, n_seed, cfg.k, cfg.metric, rev_capacity=cfg.rev_cap,
-            dispatch=cfg.dispatch,
-        )
-        start = n_seed
-        # seed-graph comparisons count toward the scanning rate
-        pre_charge = n_seed * (n_seed - 1) // 2
-        if coarse is None and cfg.seed_mode == "coarse":
-            key, ck = jax.random.split(key)
-            coarse, coarse_comps = hierarchy.build_coarse(
-                x, cfg, ck, assign_rows=jnp.arange(n_seed, dtype=jnp.int32)
+        with trk.span("build/seed"):
+            n_seed = min(cfg.n_seed_init, n)
+            g = brute.exact_seed_graph(
+                x, n_seed, cfg.k, cfg.metric, rev_capacity=cfg.rev_cap,
+                dispatch=cfg.dispatch,
             )
-            pre_charge += coarse_comps
+            start = n_seed
+            # seed-graph comparisons count toward the scanning rate
+            pre_charge = n_seed * (n_seed - 1) // 2
+            if coarse is None and cfg.seed_mode == "coarse":
+                key, ck = jax.random.split(key)
+                coarse, coarse_comps = hierarchy.build_coarse(
+                    x, cfg, ck, assign_rows=jnp.arange(n_seed, dtype=jnp.int32)
+                )
+                pre_charge += coarse_comps
     stats = zero_stats(pre_charge)
     W = cfg.wave
-
-    from repro.obs import NOOP  # late: keep core importable without obs init
-
-    trk = tracker if tracker is not None else NOOP
     pos = int(start)
     n_waves = 0
     while pos < n:
@@ -487,17 +490,20 @@ def build(
         with trk.span("build/stride") as sp:
             stride_end = n_waves + callback_stride
             while pos < n and n_waves < stride_end:
-                key, sk = jax.random.split(key)
-                if coarse is None:
-                    g, stats = wave_step(
-                        g, x, jnp.asarray(pos, jnp.int32), sk, stats, cfg,
-                        enc=enc,
-                    )
-                else:
-                    g, stats, coarse = wave_step(
-                        g, x, jnp.asarray(pos, jnp.int32), sk, stats, cfg,
-                        coarse=coarse, enc=enc,
-                    )
+                # the wave's dispatch only: no sync, the device ops that
+                # follow it carry the device time
+                with trk.span("build/wave"):
+                    key, sk = jax.random.split(key)
+                    if coarse is None:
+                        g, stats = wave_step(
+                            g, x, jnp.asarray(pos, jnp.int32), sk, stats, cfg,
+                            enc=enc,
+                        )
+                    else:
+                        g, stats, coarse = wave_step(
+                            g, x, jnp.asarray(pos, jnp.int32), sk, stats, cfg,
+                            coarse=coarse, enc=enc,
+                        )
                 pos += min(W, n - pos)
                 n_waves += 1
             sp.sync(g.nbr_ids)

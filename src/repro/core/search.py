@@ -372,13 +372,18 @@ def init_state(
                 "CoarseLevel) passed as the `coarse` operand"
             )
         key_c, key_r = jax.random.split(key)
-        cres = search(coarse.graph, coarse.points, q, key_c, coarse_config(cfg))
-        win = cres.ids  # (B, T) landmark indices, -1 padded
-        safe_win = jnp.maximum(win, 0)
-        lm_rows = jnp.where(win >= 0, coarse.landmark_rows[safe_win], -1)
-        members = jnp.where(
-            win[:, :, None] >= 0, coarse.members[safe_win], -1
-        ).reshape(B, -1)
+        # device scope read by the benchmark as the coarse pass's time; the
+        # nested search's own ``expand`` falls under it
+        with jax.named_scope("coarse_pass"):
+            cres = search(
+                coarse.graph, coarse.points, q, key_c, coarse_config(cfg)
+            )
+            win = cres.ids  # (B, T) landmark indices, -1 padded
+            safe_win = jnp.maximum(win, 0)
+            lm_rows = jnp.where(win >= 0, coarse.landmark_rows[safe_win], -1)
+            members = jnp.where(
+                win[:, :, None] >= 0, coarse.members[safe_win], -1
+            ).reshape(B, -1)
         rand = jax.random.randint(
             key_r, (B, cfg.n_seeds), 0, jnp.maximum(g.n_valid, 1),
             dtype=jnp.int32,
@@ -487,9 +492,11 @@ def search(
         )
     st = init_state(g, x, q, key, cfg, coarse=coarse, enc=enc)
     step = _make_step(g, x, q, cfg, enc)
-    st = jax.lax.while_loop(
-        lambda s: (~jnp.all(s.done)) & (s.it < cfg.max_iters), step, st
-    )
+    # device scope read by the benchmark as the expansion loop's time
+    with jax.named_scope("expand"):
+        st = jax.lax.while_loop(
+            lambda s: (~jnp.all(s.done)) & (s.it < cfg.max_iters), step, st
+        )
     return SearchResult(
         ids=st.beam_ids[:, : cfg.k],
         dists=st.beam_dist[:, : cfg.k],

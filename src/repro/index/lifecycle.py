@@ -136,7 +136,9 @@ class OnlineIndex:
         """Index ``items`` with online LGD/OLG construction.
 
         ``capacity > n`` pre-allocates headroom; either way later inserts
-        auto-grow, so capacity is a hint, not a ceiling.
+        auto-grow, so capacity is a hint, not a ceiling.  The build runs
+        under an ``index/build`` span of ``obs.NOOP``: a profiler trace
+        shows it, no event log does (no tracker is attached yet).
         """
         if cfg is None:
             cfg = construct.BuildConfig(**cfg_kw)
@@ -147,10 +149,11 @@ class OnlineIndex:
             )
         n = items.shape[0]
         cap = capacity or n
-        g, _, coarse = construct.build(items, cfg, key, return_coarse=True)
-        if cap > n:
-            g = graph_lib.grow_graph(g, cap)
-            items = jnp.pad(items, ((0, cap - n), (0, 0)))
+        with NOOP.span("index/build"):
+            g, _, coarse = construct.build(items, cfg, key, return_coarse=True)
+            if cap > n:
+                g = graph_lib.grow_graph(g, cap)
+                items = jnp.pad(items, ((0, cap - n), (0, 0)))
         return cls(
             graph=g,
             items=items,
@@ -421,21 +424,23 @@ class OnlineIndex:
         and score convention live in ``serve.retrieval.retrieve``.  Serving
         inherits the builder's precision (``search_config``); the compressed
         companion table is cached on the index and re-derived only after
-        catalog churn.
+        catalog churn.  The call runs under an ``index/search`` span, which
+        marks the dispatch and does not sync.
         """
-        self.flush()
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        scfg = self.search_config(top_k, beam)
-        coarse = None
-        if scfg.seed_mode == "coarse":
-            coarse = self._ensure_coarse()
-            if coarse is None:  # nothing alive to derive from
-                scfg = dataclasses.replace(scfg, seed_mode="random")
-        return search_lib.search(
-            self.graph, self.items, queries, key, scfg, coarse=coarse,
-            enc=self._ensure_enc(),
-        )
+        with (self.tracker or NOOP).span("index/search"):
+            self.flush()
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            scfg = self.search_config(top_k, beam)
+            coarse = None
+            if scfg.seed_mode == "coarse":
+                coarse = self._ensure_coarse()
+                if coarse is None:  # nothing alive to derive from
+                    scfg = dataclasses.replace(scfg, seed_mode="random")
+            return search_lib.search(
+                self.graph, self.items, queries, key, scfg, coarse=coarse,
+                enc=self._ensure_enc(),
+            )
 
     # -- persistence ---------------------------------------------------------
 

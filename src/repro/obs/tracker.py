@@ -32,6 +32,12 @@ Spans nest (a ``serve/step`` span contains an ``index/flush`` span and an
 stamps each span event with its ``depth`` and ``parent`` so the JSONL
 round-trips back into a tree.
 
+Every span, under every tracker ``NoopTracker`` included, is also a
+``jax.profiler.TraceAnnotation`` of the same name: a profiler trace of the
+process carries the program's spans on its host plane, on the clock of the
+device's ops, with the JSONL's vocabulary.  With the profiler off a
+TraceAnnotation records nothing.
+
 Trackers never change results: they only read host scalars and timestamps.
 ``tests/test_obs.py`` pins that searching with a tracker attached is
 bit-identical to searching without one (fp32).
@@ -45,6 +51,7 @@ import time
 from typing import Iterator, List, Mapping, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracker",
@@ -184,14 +191,16 @@ class Tracker:
 
 
 class _SpanCtx:
-    __slots__ = ("_tracker", "_name", "_span")
+    __slots__ = ("_tracker", "_name", "_span", "_annotation")
 
     def __init__(self, tracker: Tracker, name: str):
         self._tracker = tracker
         self._name = name
         self._span: Optional[Span] = None
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self) -> Span:
+        self._annotation.__enter__()
         self._tracker._stack.append(self._name)
         self._span = Span(self._name, self._tracker)
         return self._span
@@ -201,37 +210,34 @@ class _SpanCtx:
             self._tracker._close_span(self._span)
         finally:
             self._tracker._stack.pop()
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
 class NoopTracker(Tracker):
     """The default: accepts everything, records nothing, syncs nothing.
 
-    ``span`` skips the stack and the clock entirely, so instrumented code
-    paths cost a single attribute check when telemetry is off.
+    ``span`` skips the stack and the clock entirely and emits no event; its
+    one cost is the span's ``TraceAnnotation``, which a profiler trace of
+    the process records and which is otherwise inert.
     """
 
     def log_metrics(self, metrics, *, step=None) -> None:
         pass
 
     def span(self, name: str):
-        return _NOOP_CTX
+        return _NoopCtx(name)
 
     def _emit(self, event: dict) -> None:
         pass
 
 
-class _NoopCtx:
-    __slots__ = ()
+class _NoopCtx(TraceAnnotation):
+    """The profiler annotation alone; yields the shared ``_NOOP_SPAN``."""
 
     def __enter__(self):
+        super().__enter__()
         return _NOOP_SPAN
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NOOP_CTX = _NoopCtx()
 
 #: module-level shared no-op instance — instrumented code uses
 #: ``tracker or NOOP`` so the hot path never branches on None twice

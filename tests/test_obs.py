@@ -4,7 +4,8 @@ Three contracts pinned here:
 
   * **span machinery** — nesting depth/parent stamps, close ordering
     (inner spans emit before their enclosing span), the ``synced`` flag,
-    and the Noop tracker's no-sync/no-alloc behaviour;
+    the Noop tracker's no-clock/no-sync/no-event behaviour, and every
+    span reaching a profiler trace as a TraceAnnotation;
   * **JSONL crash safety** — append mode, flush-per-event (events are
     readable while the tracker is still open), round-trip through
     ``load_events`` with a torn tail skipped rather than fatal;
@@ -96,15 +97,30 @@ def test_span_stack_unwinds_on_exception():
     assert "span" not in trk.metrics_events[-1]
 
 
-def test_noop_tracker_is_inert_and_allocation_free():
+def test_noop_tracker_is_inert_and_allocation_free(monkeypatch):
+    """No clock read, no sync, no event: a Noop span's one cost is its
+    profiler TraceAnnotation; the span it yields is one shared object."""
+    import time
+
+    from repro.obs import tracker as tracker_mod
+
     trk = NoopTracker()
-    ctx1, ctx2 = trk.span("a"), trk.span("b")
-    assert ctx1 is ctx2  # shared singleton: no per-span allocation
+
+    def forbidden(*_a, **_k):
+        raise AssertionError("NoopTracker touched the clock or synced")
+
+    monkeypatch.setattr(time, "perf_counter", forbidden)
+    monkeypatch.setattr(jax, "block_until_ready", forbidden)
+    monkeypatch.setattr(trk, "_emit", forbidden)
     x = jnp.arange(3.0)
-    with trk.span("a") as sp:
-        assert sp.sync(x) is x  # passthrough — no block_until_ready
-        sp.synced = True  # annotation writes are discarded, not errors
-        assert sp.synced is False
+    with trk.span("a") as sp1:
+        assert sp1.sync(x) is x  # passthrough — no block_until_ready
+        sp1.synced = True  # annotation writes are discarded, not errors
+        assert sp1.synced is False
+        with trk.span("b") as sp2:
+            assert sp2 is sp1  # shared singleton span: no per-span state
+    assert sp1 is tracker_mod._NOOP_SPAN
+    assert trk._stack == []  # the span stack is never touched
     trk.log_metrics({"k": 1}, step=0)
     trk.finish()
     assert isinstance(NOOP, NoopTracker)
@@ -292,6 +308,54 @@ def test_build_bitwise_identical_with_tracker():
     # and the tracker actually saw the build
     assert trk.spans("build/stride")
     assert any("build/n_comps" in e["metrics"] for e in trk.metrics_events)
+
+
+def test_program_spans_reach_the_profiler_trace(tmp_path):
+    """Every tracker span is a profiler TraceAnnotation, NOOP's included: a
+    CPU profiler trace of a tiny build + search carries the program's own
+    spans on the host plane, nested and in order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    x = _mk_items(n=96)
+    cfg = construct.BuildConfig(k=4, wave=16, n_seed_init=32, max_iters=8,
+                                seed_mode="coarse")
+    key = jax.random.PRNGKey(1)
+    OnlineIndex.build(x, cfg, key=key).search(x[:4], 3)  # compile first
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        idx = OnlineIndex.build(x, cfg, key=key)
+        jax.block_until_ready(idx.search(x[:4], 3).ids)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("index/build", "build/seed", "build/stride",
+                                  "build/wave", "index/search"):
+                        spans.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+    assert sorted(spans) == ["build/seed", "build/stride", "build/wave",
+                             "index/build", "index/search"]
+    (build,), (search,) = spans["index/build"], spans["index/search"]
+
+    def inside(inner, outer):
+        return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+    # the seed graph and the coarse level's own build: under index/build
+    assert all(inside(s, build) for s in spans["build/seed"])
+    # one build/wave per wave, each inside a build/stride, all in the build
+    waves = sorted(spans["build/wave"])
+    assert len(waves) >= (96 - 32) // 16
+    assert all(any(inside(w, s) for s in spans["build/stride"]) for w in waves)
+    assert all(inside(w, build) for w in waves)
+    # in order: the outer seed graph before the first wave, search after build
+    assert min(spans["build/seed"])[0] < waves[0][0]
+    assert search[0] >= build[1]
 
 
 def test_serving_loop_bitwise_identical_with_tracker():
