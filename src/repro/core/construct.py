@@ -142,22 +142,6 @@ def scanning_rate(stats: BuildStats, n: int) -> float:
 # ---------------------------------------------------------------------------
 
 
-def _lookup_D(
-    vis_ids: Array,  # (W, H) per-wave-lane tables
-    vis_dist: Array,
-    lane: Array,  # (T,) which lane's table to consult
-    ids: Array,  # (T, k) ids to look up
-    probes: int,
-) -> Array:
-    """D(q_lane, ids): distance if the search computed it, else ∞ (Rule 1/3)."""
-    H = vis_ids.shape[1]
-    slots = search_lib._probe_slots(ids, H, probes)  # (T, k, P)
-    got_ids = vis_ids[lane[:, None, None], slots]
-    got_d = vis_dist[lane[:, None, None], slots]
-    hit = got_ids == ids[..., None]
-    return jnp.min(jnp.where(hit, got_d, jnp.inf), axis=-1)
-
-
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def commit_wave(
     g: KNNGraph,
@@ -230,7 +214,13 @@ def commit_wave(
         # D(q, member_j): wave-wave pairs from the intra tile, others from the
         # visited hash (∞ when the search never compared them — Rule 1).
         is_wave = (row_ids >= q_start) & (row_ids < q_start + W)
-        D_hash = _lookup_D(res.vis_ids, res.vis_dist, lane_flat, row_ids, probes)
+        with jax.named_scope("d_lookup"):
+            # a lane's candidate rows are contiguous in t (lane_flat), so
+            # the (T, k) ids are (W, T // W * k), one row per lane's table
+            D_hash = ops.visited_lookup(
+                res.vis_ids, res.vis_dist, row_ids.reshape(W, -1),
+                probes, dispatch=cfg.dispatch,
+            ).reshape(T, k)
         if cfg.intra_wave and W > 1:
             w_idx = jnp.clip(row_ids - q_start, 0, W - 1)
             D_wave = tile[lane_flat[:, None], w_idx]

@@ -175,7 +175,6 @@ class SearchResult(NamedTuple):
 
 # The hash/beam primitives live next to the fused kernel that consumes them
 # (kernels.expand); these aliases keep the established core-layer surface.
-_probe_slots = expand_lib.probe_slots
 hash_lookup = expand_lib.hash_lookup
 _hash_probe_state = expand_lib.hash_probe_state
 _dedupe_beam = expand_lib.dedupe_beam

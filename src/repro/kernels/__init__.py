@@ -17,6 +17,10 @@ Kernels:
                       top-k merge in one kernel, with the bit-identical
                       pure-jnp ``expand_reference`` beside it
                       (``ops.expand_step`` is the three-way dispatcher).
+  * ``visited_lookup`` — the LGD commit's D(q, x) from each wave lane's
+                      visited-hash table as a dense compare on the vector
+                      unit, in place of per-id probe gathers
+                      (``ops.visited_lookup``).
 """
 
 from repro.kernels import expand, ops, ref

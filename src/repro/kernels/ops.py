@@ -29,6 +29,10 @@ execution-surface policies of the framework are resolved HERE and only here:
       gathers, scatter, ``lax.top_k``) does not lower to Mosaic.  It runs
       ``expand.expand_reference`` — the XLA probe/record/merge — with
       ``pallas_distances`` set by the same ``gather_distance`` predicate.
+    - ``visited_lookup``: the dense per-lane compare kernel when
+      ``visited_lookup.kernel_fits(H)`` — visited tables of up to
+      ``visited_lookup.MAX_SLOTS`` slots — and the probe gathers of
+      ``expand.hash_lookup`` (the reference) for larger tables.
 
   ``engines`` reports these choices for a table, so callers can print what
   ran.  The legacy ``use_pallas`` keyword is still accepted (None/True/False
@@ -63,6 +67,7 @@ from repro.kernels import expand as _expand
 from repro.kernels import gather_dist as _gather_dist
 from repro.kernels import precision as _precision
 from repro.kernels import ref as _ref
+from repro.kernels import visited_lookup as _visited_lookup
 
 Array = jax.Array
 
@@ -105,9 +110,11 @@ def resolve_dispatch(
 
 
 def engines(
-    dispatch: Optional[str], dtype, d: int
+    dispatch: Optional[str], dtype, d: int,
+    hash_slots: int = _visited_lookup.MAX_SLOTS,
 ) -> dict[str, str]:
-    """Which engine each op runs for an (n, d) table of ``dtype``.
+    """Which engine each op runs for an (n, d) table of ``dtype`` and, for
+    ``visited_lookup``, visited tables of ``hash_slots`` slots.
 
     Values: ``"pallas"`` (compiled kernel), ``"pallas-interpret"``,
     ``"xla"`` (pure-JAX reference), and for ``expand_step`` also
@@ -130,6 +137,7 @@ def engines(
         "pairwise_distance": name(*resolve_dispatch(dispatch)),
         "gather_distance": name(*gather),
         "expand_step": expand,
+        "visited_lookup": name(*_lookup_engine(dispatch, hash_slots)),
     }
 
 
@@ -141,6 +149,14 @@ def _gather_engine(
     ``gather_dist.kernel_fits``."""
     return resolve_dispatch(
         dispatch, use_pallas, fits=_gather_dist.kernel_fits(dtype, d)
+    )
+
+
+def _lookup_engine(dispatch: Optional[str], hash_slots: int) -> tuple[bool, bool]:
+    """``resolve_dispatch`` for the visited-lookup kernel over tables of
+    ``hash_slots`` slots: compiled where ``visited_lookup.kernel_fits``."""
+    return resolve_dispatch(
+        dispatch, fits=_visited_lookup.kernel_fits(hash_slots)
     )
 
 
@@ -279,6 +295,30 @@ def merge_proposals(
     )
     live = hop >= 0
     return hop, jnp.where(live, d, jnp.inf), jnp.sum(live, dtype=jnp.int32)
+
+
+def visited_lookup(
+    vis_ids: Array,
+    vis_dist: Array,
+    ids: Array,
+    probes: int,
+    *,
+    dispatch: Optional[str] = None,
+) -> Array:
+    """D(q_w, ids[w, m]) from per-lane visited tables: (W, H) tables and
+    (W, M) ids -> (W, M) float32, the distance lane w's search recorded for
+    the id within its ``probes``-slot window, +inf where it recorded none.
+
+    The compiled kernel (``kernels.visited_lookup``, a dense compare against
+    each lane's whole table) returns the reference's float for every id >= 0;
+    the reference is ``expand.hash_lookup``'s probe gathers.
+    """
+    use_kernel, interpret = _lookup_engine(dispatch, vis_ids.shape[1])
+    if use_kernel:
+        return _visited_lookup.visited_lookup(
+            vis_ids, vis_dist, ids, probes, interpret=interpret
+        )
+    return _expand.hash_lookup(vis_ids, vis_dist, ids, probes)[1]
 
 
 def topk_smallest(dists: Array, ids: Array, k: int):

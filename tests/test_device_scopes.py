@@ -59,6 +59,21 @@ def test_wave_step_ops_fall_under_search_and_commit(built, scope):
     assert not any(other in _segments(n) for n in mine)
 
 
+def test_d_lookup_ops_fall_under_wave_commit(built):
+    """The commit's D lookup (``ops.visited_lookup``) runs under its own
+    ``d_lookup`` scope, inside ``wave_commit``'s ``commit_wave``."""
+    x, cfg, g, stats, coarse = built
+    text = jax.jit(construct.wave_core, static_argnames=("cfg",)).lower(
+        g, x, jnp.asarray(64, jnp.int32), jax.random.PRNGKey(2), stats, cfg,
+        coarse=coarse,
+    ).compile().as_text()
+    mine = [_segments(n) for n in _op_names(text) if "d_lookup" in _segments(n)]
+    assert mine
+    for seg in mine:
+        assert seg.index("wave_commit") < seg.index("jit(commit_wave)") < seg.index("d_lookup")
+        assert "wave_search" not in seg
+
+
 def test_search_ops_fall_under_coarse_pass_and_expand(built):
     x, cfg, g, _, coarse = built
     scfg = cfg.search_config()

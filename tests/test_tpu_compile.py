@@ -113,6 +113,19 @@ def test_auto_expand_step_compiles(on_tpu):
     assert "tpu_custom_call" in text
 
 
+def test_visited_lookup_compiles(on_tpu):
+    """The LGD commit's D lookup at the build cell's shape: W 4,096 lanes,
+    M = ins_cap 60 x k 20 ids each, tables of H 2,048 slots."""
+    s = on_tpu
+    W, M = 4096, 60 * 20
+    assert ops.engines("auto", jnp.float32, D, hash_slots=H)["visited_lookup"] == "pallas"
+    text = compile_text(
+        lambda vi, vd, i: ops.visited_lookup(vi, vd, i, 8, dispatch="auto"),
+        s((W, H), jnp.int32), s((W, H)), s((W, M), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("cached", [True, False])
 def test_pairwise_l2_compiles(on_tpu, cached):
     s = on_tpu
