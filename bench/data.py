@@ -1,10 +1,13 @@
 """Seeded catalogs and query pools, made on the device in one jitted call.
 
-The generator is a copy of ``repro.data.synthetic.clustered`` (the benchmark
-keeps its own yardstick: a later change to the program's generators cannot
-change the data a cell runs on).  A configuration names its generator,
-parameters and data seed; ``make`` draws ``n_rows`` rows from one key, so the
-catalog and its held-out queries share one distribution.
+The generators are copies of the program's (``clustered`` here, of
+``repro.data.synthetic.clustered``; each other one in
+``bench/generators/<name>.py`` as the function of that name): the benchmark
+keeps its own yardstick, so a later change to the program's generators
+cannot change the data a cell runs on.  A configuration names its
+generator, parameters and data seed; ``make`` draws ``n_rows`` rows from one
+key, so the catalog and its held-out queries share one distribution.  A new
+generator is a new file: nothing here lists it.
 """
 
 from __future__ import annotations
@@ -13,6 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench import plugins
 
 
 def clustered(key, n, d, *, n_clusters=256, intrinsic_dim=16, noise=0.05):
@@ -30,6 +35,14 @@ def clustered(key, n, d, *, n_clusters=256, intrinsic_dim=16, noise=0.05):
 GENERATORS = {"clustered": clustered}
 
 
+def generator(name: str):
+    """``GENERATORS[name]``, else the function ``name`` of
+    ``bench/generators/<name>.py``; ``FileNotFoundError`` for neither."""
+    if name in GENERATORS:
+        return GENERATORS[name]
+    return getattr(plugins.load("generators", name), name)
+
+
 def seed_key(seed: int, stream: int = 0):
     """A PRNG key for any non-negative seed, also past 32 bits."""
     seed = int(seed)
@@ -40,7 +53,7 @@ def seed_key(seed: int, stream: int = 0):
 
 @functools.partial(jax.jit, static_argnames=("kind", "n", "d", "params"))
 def _make(key, kind, n, d, params):
-    return GENERATORS[kind](key, n, d, **dict(params))
+    return generator(kind)(key, n, d, **dict(params))
 
 
 def make(data_cfg: dict, key, n_rows: int, d: int):

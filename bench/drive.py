@@ -1,4 +1,9 @@
-"""The kinds of traffic a mix can name, each driven through the program.
+"""The built-in kinds of traffic a mix can name, each driven through the
+program: ``batch`` and ``build``.  Another kind ``<kind>`` is a file of its
+own, ``bench/drivers/<kind>.py``, whose ``run(cell, ready)`` is a driver as
+these are (``bench/run.py`` finds it by the mix's ``kind``); it can use the
+helpers here.  Adding a traffic kind takes that file, a mix and an entry
+only, and no edit here.
 
 A driver gets the run's ``Cell`` (configuration, mix, seed, seconds) and
 returns an ``Outcome``: the end-to-end numbers, the numbers compared with the

@@ -6,10 +6,13 @@ The cell is an entry of ``workloads`` in ``BENCHMARK.json``.  It names a
 configuration (``bench/configs/<config>.json``: the catalog's shape, its
 data generator and data seed, the ``BuildConfig`` and the limits of the
 comparison) and a traffic mix (``bench/mixes/<traffic>.json``, whose
-``kind`` picks the driver in ``bench/drive.py``).  A per-layer metric
-``a.b.c`` is read by ``bench/metrics/a.b.c.py``, or else by the file of its
-longest prefix (``a.b.py``).  Nothing here names a cell: adding one takes
-new files and entries only.
+``kind`` picks the driver: ``drive.DRIVERS[kind]``, else ``run`` of
+``bench/drivers/<kind>.py``).  A generator the configuration names is found
+the same way (``bench/data.py``: ``data.GENERATORS``, else
+``bench/generators/<name>.py``).  A per-layer metric ``a.b.c`` is read by
+``bench/metrics/a.b.c.py``, or else by the file of its longest prefix
+(``a.b.py``).  Nothing here names a cell, a traffic kind or a generator:
+adding one takes new files and entries only.
 
 The run refuses anything but a TPU with as many chips as the cell asks for,
 unless ``--rehearse`` is given: that runs on whatever JAX finds (the CPU
@@ -32,7 +35,6 @@ from __future__ import annotations
 T_START = __import__("time").perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -97,15 +99,22 @@ class CompileClock:
 def reader(name: str):
     """The module that reads per-layer metric ``name``: the file of the name
     or of its longest dotted prefix under ``bench/metrics``."""
+    from bench import plugins
+
     parts = name.split(".")
     for i in range(len(parts), 0, -1):
-        path = os.path.join(BENCH, "metrics", ".".join(parts[:i]) + ".py")
-        if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location("bench_metric_" + name, path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod
+        prefix = ".".join(parts[:i])
+        if os.path.exists(plugins.path("metrics", prefix)):
+            return plugins.load("metrics", prefix)
     raise FileNotFoundError(f"no reader for per-layer metric {name!r} under bench/metrics")
+
+
+def driver(kind: str):
+    """The driver of a traffic kind: ``drive.DRIVERS[kind]``, else ``run`` of
+    ``bench/drivers/<kind>.py``; ``FileNotFoundError`` for neither."""
+    from bench import drive, plugins
+
+    return drive.DRIVERS.get(kind) or plugins.load("drivers", kind).run
 
 
 def reports(metric: dict, workload: str, e2e_names: set | None = None) -> bool:
@@ -163,7 +172,7 @@ def run(args) -> dict:
         marks["setup_s"] = time.perf_counter() - T_START
         marks["compiles"] = clock.snapshot()
 
-    outcome = drive.DRIVERS[mix["kind"]](cell, ready)
+    outcome = driver(mix["kind"])(cell, ready)
     window_compiles = clock.since(marks["compiles"])
     if cell.trace:
         jax.profiler.stop_trace()
